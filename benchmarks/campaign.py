@@ -70,6 +70,7 @@ def main(argv: list[str] | None = None) -> int:
                                 expand_campaign, format_campaigns,
                                 get_campaign, load_section, run_cells,
                                 write_bench)
+    from repro.launch.compile_cache import enable_compile_cache
 
     if args.list:
         print(format_campaigns())
@@ -86,6 +87,7 @@ def main(argv: list[str] | None = None) -> int:
             print(f"# masked {cell_id}: {why}")
         return 0
 
+    enable_compile_cache()            # the cell children inherit it
     done = [0]
 
     def progress(rec: dict) -> None:

@@ -13,12 +13,9 @@ most representative of the paper's technique):
 granite cells run at a fixed L=12 (unrolled) so before/after compare the
 same program family; the full-depth numbers in §Roofline extrapolate.
 """
-import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=512")
-
 import argparse
 import json
+import os
 import time
 
 OUT = "results/hillclimb"
@@ -100,12 +97,17 @@ RUNS = [
 
 
 def main():
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", default=None, choices=["A", "B", "C"])
     ap.add_argument("--tags", default=None, help="comma list to (re)run")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.dryrun import run_cell
+
+    enable_compile_cache()
     os.makedirs(OUT, exist_ok=True)
     tags = set(args.tags.split(",")) if args.tags else None
 

@@ -45,6 +45,9 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else None
 
     from benchmarks import paper_figs, serving_bench
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     results: dict[str, object] = {}
     print("name,us_per_call,derived")

@@ -11,7 +11,6 @@ server receives the maximum bonus rather than an infinity.
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.types import PricingModel, TenantState, Weights
@@ -111,6 +110,8 @@ def batch_scores(policy: str, premium, ordinal, age, loyalty, requests, users,
                  w: Weights = Weights()):
     """Vectorised scorer over N tenants (jnp arrays). Semantics identical
     to the scalar form; used by the overhead benchmark at large N."""
+    import jax.numpy as jnp
+
     premium = jnp.asarray(premium, jnp.float32)
     base = (w.W_P * premium
             + w.W_ID / jnp.maximum(jnp.asarray(ordinal, jnp.float32), 1.0)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_final_ref, s_ref, *,
             chunk: int):
@@ -96,7 +94,7 @@ def rwkv6_forward(r, k, v, w, u, *, chunk: int = 64, interpret: bool = False):
             jax.ShapeDtypeStruct((B, H, K, K), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, y_ref, s_final_ref,
             s_ref, *, chunk: int):
@@ -92,7 +90,7 @@ def ssd_forward(x, dt, a_log, Bm, Cm, *, chunk: int = 128,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a_log, Bm[:, None], Cm[:, None])

@@ -4,16 +4,15 @@ production meshes and extract memory/cost/collective analysis.
   PYTHONPATH=src python -m repro.launch.dryrun --arch granite-8b \
       --shape train_4k --mesh single --out results/dryrun
 
-The XLA_FLAGS assignment below MUST run before any other import (jax
-locks the device count at first init); 512 placeholder host devices back
-both the 16×16 single-pod and 2×16×16 multi-pod meshes. Compilation is
-AOT — no arrays are ever allocated at these shapes.
+``main`` sets XLA_FLAGS before jax first initialises its backend (which
+locks the device count); 512 placeholder host devices back both the
+16×16 single-pod and 2×16×16 multi-pod meshes. Importing this module
+changes no device state. Compilation is AOT — no arrays are ever
+allocated at these shapes.
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -265,6 +264,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main():
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True, choices=list(SHAPES))

@@ -1,8 +1,8 @@
 """Production mesh definitions.
 
 A function, not a module-level constant: importing this module must never
-touch jax device state (smoke tests see 1 CPU device; only dryrun.py sets
-XLA_FLAGS for 512 placeholder devices).
+touch jax device state (smoke tests see 1 CPU device; only the dry-run
+entry points' ``main`` set XLA_FLAGS for 512 placeholder devices).
 """
 from __future__ import annotations
 

@@ -14,12 +14,9 @@ Cost control on the CPU backend:
   * multi-pod cells compile with scan_layers=True: that pass proves the
     ("pod","data","model") sharding is coherent, not the roofline numbers.
 """
-import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=512")
-
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -111,6 +108,8 @@ def run_one(arch, shape_name, mesh_kind, out_dir, tag="baseline"):
 
 
 def main():
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--mesh", default="both", choices=["single", "multi", "both"])

@@ -230,7 +230,11 @@ def full_attention_reference(q, k, v, *, causal=True, window: int = 0):
 def attention(params, x, cfg: ModelConfig, positions, *, causal=True):
     """Full attention block for train/prefill. Returns (out, (k, v))."""
     q, k, v = qkv_proj(params, x, cfg, positions)
-    if cfg.use_pallas and jax.default_backend() == "tpu":
+    if cfg.use_pallas:
+        if jax.default_backend() != "tpu":
+            raise ValueError(
+                f"{cfg.name}: use_pallas needs a TPU, but the default "
+                f"backend is {jax.default_backend()!r}")
         from repro.kernels.ops import flash_attention as _fa
         o = _fa(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
                 causal=causal,

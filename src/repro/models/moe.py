@@ -192,8 +192,7 @@ def moe_ffn_tp(params, x, cfg: ModelConfig):
 
     pspec = jax.tree_util.tree_map_with_path(wspec, params)
     manual = set(daxes) | ({"model"} if f_ok else set())
-    from repro.parallel.sharding import shard_map
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(d, None, None), pspec),
         out_specs=(P(d, None, None), P()),

@@ -28,34 +28,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _ctx = threading.local()
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, axis_names=None,
-              check_vma: bool | None = None):
-    """``jax.shard_map`` with the new keywords, on any jax version.
-
-    Older jax only ships ``jax.experimental.shard_map.shard_map`` whose
-    knobs are inverted: ``auto`` lists the NON-manual axes (vs
-    ``axis_names`` listing the manual ones) and ``check_rep`` is the old
-    name of ``check_vma``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        if auto:
-            kw["auto"] = auto
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def current_mesh() -> Mesh | None:
     return getattr(_ctx, "mesh", None)
 

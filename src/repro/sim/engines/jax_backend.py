@@ -5,8 +5,7 @@ Ports the :class:`~repro.sim.edgesim.FleetStepper` chunk math to
 evaluation run as one fused (rows × max-requests) float32 kernel, with
 only the ragged per-request bookkeeping (flat extraction, per-second
 violation attribution, Monitor feeds) left to numpy. On a multi-device
-runtime the row axis is sharded across devices with the existing
-:func:`repro.parallel.sharding.shard_map` shim.
+runtime the row axis is sharded across devices with ``jax.shard_map``.
 
 RNG scheme (``counter-jax``): every draw comes from a counter-based
 threefry stream whose 64-bit key_data is a vectorized splitmix32 mix::
@@ -56,7 +55,7 @@ GameWorkload); anything else raises with a pointer at
 ``SimConfig.backend_options`` knobs: ``shard`` (bool, default True —
 shard rows over devices when more than one is visible) and ``pallas``
 (bool, default False — route the latency-scale factor through the fused
-Pallas kernel, interpret-mode on CPU).
+Pallas kernel, which compiles for the TPU only).
 """
 from __future__ import annotations
 
@@ -67,6 +66,7 @@ import zlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.sim.edgesim import FleetStepper
 from repro.sim.engines.base import EngineBackend
@@ -155,14 +155,13 @@ def _scale_kernel(base_ref, alpha_ref, demand_ref, cap_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _pallas_latency_scale(base_pf, alpha, demand, capacity, interpret=None):
+def _pallas_latency_scale(base_pf, alpha, demand, capacity,
+                          interpret=False):
     """base·pf·max(1, demand/capacity)^α as a Pallas kernel over row
-    blocks (``backend_options={"pallas": True}``). Interpret-mode is the
-    CPU fallback, same pattern as :mod:`repro.kernels.ops`."""
+    blocks (``backend_options={"pallas": True}``). It compiles for the
+    TPU; off the TPU only ``interpret=True`` runs it."""
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     T, W = demand.shape
     bT = min(T, 256)
     grid = (-(-T // bT),)
@@ -200,8 +199,6 @@ class JaxFleetStepper(FleetStepper):
         if opts.get("shard", True):
             devs = jax.devices()
             if len(devs) > 1:
-                from repro.parallel.sharding import Mesh
-
                 self._mesh = Mesh(np.array(devs), ("data",))
                 self._ndev = len(devs)
 
@@ -285,17 +282,15 @@ class JaxFleetStepper(FleetStepper):
         if f is None:
             f = impl
             if self._mesh is not None:
-                from repro.parallel.sharding import P, shard_map
-
                 spec = P("data")
                 # check_vma=False: the poisson sampler's internal while
                 # loop has no replication rule, and every kernel here is
                 # row-local anyway
-                f = shard_map(f, self._mesh,
-                              in_specs=(spec,) * n_args,
-                              out_specs=(spec,) * n_out if n_out > 1
-                              else spec,
-                              check_vma=False)
+                f = jax.shard_map(f, mesh=self._mesh,
+                                  in_specs=(spec,) * n_args,
+                                  out_specs=(spec,) * n_out if n_out > 1
+                                  else spec,
+                                  check_vma=False)
             f = jax.jit(f)
             _KERNEL_CACHE[key] = f
         return f

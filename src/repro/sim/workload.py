@@ -416,7 +416,7 @@ def _jax_latency_scale(base_pf, alpha, demand, capacity) -> np.ndarray:
             return base_pf[:, None] * jnp.maximum(1.0, rho) ** alpha[:, None]
 
         _jax_scale_fn = f
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         return np.asarray(_jax_scale_fn(base_pf, alpha, demand, capacity))
 
 

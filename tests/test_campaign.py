@@ -15,6 +15,7 @@ from repro.campaign import (CampaignSpec, SweepGrid, Tolerances,
                             expand_grid, get_campaign, load_bench,
                             load_section, run_cells, write_bench)
 from repro.campaign.benchio import SCHEMA_VERSION
+from repro.campaign.executor import uses_device
 from repro.campaign.registry import MAIN_GRID
 from repro.campaign.spec import OPTION_ENGINES
 
@@ -103,17 +104,32 @@ def _fake_ok(cell, quick):
     return rec
 
 
+# cell functions are pickled to spawned children, so they live at
+# module level
+def _raise_on_batched(cell, quick):
+    if cell.engine == "batched":
+        raise RuntimeError("boom")
+    return _fake_ok(cell, quick)
+
+
+def _crash_on_batched(cell, quick):
+    if cell.engine == "batched":
+        os._exit(3)                     # simulated hard crash
+    return _fake_ok(cell, quick)
+
+
+def _hang_on_batched(cell, quick):
+    if cell.engine == "batched":
+        time.sleep(60.0)
+    return _fake_ok(cell, quick)
+
+
 def test_raising_cell_does_not_abort_siblings():
     cells = expand_campaign(CampaignSpec(name="t", grids=(TINY_GRID,)))
     assert len(cells) == 2
 
-    def cell_fn(cell, quick):
-        if cell.engine == "batched":
-            raise RuntimeError("boom")
-        return _fake_ok(cell, quick)
-
     recs = run_cells(cells, quick=True, workers=2, cell_timeout_s=60.0,
-                     cell_fn=cell_fn)
+                     cell_fn=_raise_on_batched)
     by_engine = {r["engine"]: r for r in recs}
     assert by_engine["vectorized"]["status"] == "ok"
     assert by_engine["batched"]["status"] == "error"
@@ -124,14 +140,8 @@ def test_raising_cell_does_not_abort_siblings():
 
 def test_crashing_cell_recorded_not_fatal():
     cells = expand_campaign(CampaignSpec(name="t", grids=(TINY_GRID,)))
-
-    def cell_fn(cell, quick):
-        if cell.engine == "batched":
-            os._exit(3)                 # simulated hard crash
-        return _fake_ok(cell, quick)
-
     recs = run_cells(cells, quick=True, workers=2, cell_timeout_s=60.0,
-                     cell_fn=cell_fn)
+                     cell_fn=_crash_on_batched)
     by_engine = {r["engine"]: r for r in recs}
     assert by_engine["vectorized"]["status"] == "ok"
     assert by_engine["batched"]["status"] == "crash"
@@ -140,19 +150,69 @@ def test_crashing_cell_recorded_not_fatal():
 
 def test_timeout_cell_recorded_not_fatal():
     cells = expand_campaign(CampaignSpec(name="t", grids=(TINY_GRID,)))
-
-    def cell_fn(cell, quick):
-        if cell.engine == "batched":
-            time.sleep(60.0)
-        return _fake_ok(cell, quick)
-
     recs = run_cells(cells, quick=True, workers=2, cell_timeout_s=1.0,
-                     cell_fn=cell_fn)
+                     cell_fn=_hang_on_batched)
     by_engine = {r["engine"]: r for r in recs}
     assert by_engine["vectorized"]["status"] == "ok"
     assert by_engine["batched"]["status"] == "timeout"
     rep = build_report("t", recs, quick=True)
     assert any("timeout" in f for f in rep.gate_failures())
+
+
+LANE_GRID = SweepGrid(scenarios=("paper_game_32",),
+                      engines=("vectorized", "jax"), seeds=(1, 2, 3),
+                      policies=("sdps",), scaling_policies=("reactive",))
+
+
+def _stamp(cell, quick):
+    rec = _fake_ok(cell, quick)
+    t0 = time.monotonic()
+    time.sleep(0.2)
+    rec.update(pid=os.getpid(), t0=t0, t1=time.monotonic())
+    return rec
+
+
+def _crash_on_jax_seed2(cell, quick):
+    if cell.engine == "jax" and cell.seed == 2:
+        os._exit(5)
+    return _stamp(cell, quick)
+
+
+def test_uses_device():
+    cells = expand_campaign(CampaignSpec(name="t", grids=(LANE_GRID,)))
+    assert {c.engine for c in cells if uses_device(c)} == {"jax"}
+    jit = SweepGrid(scenarios=("paper_game_32",), engines=("batched",),
+                    policies=("sdps",), scaling_policies=("reactive",),
+                    backend_options=((), (("jit_scale", True),)))
+    assert [uses_device(c) for c in expand_grid(jit)[0]] == [False, True]
+
+
+def test_device_cells_run_one_after_another_in_one_child():
+    """Cells that hold the chip never run in two children at once: the
+    jax cells share one child and run in cell order without overlap,
+    while each host cell gets a child of its own."""
+    cells = expand_campaign(CampaignSpec(name="t", grids=(LANE_GRID,)))
+    recs = run_cells(cells, quick=True, workers=3, cell_timeout_s=60.0,
+                     cell_fn=_stamp)
+    assert [r["cell"] for r in recs] == [c.cell_id for c in cells]
+    dev = [r for r in recs if r["engine"] == "jax"]
+    host = [r for r in recs if r["engine"] == "vectorized"]
+    assert len(dev) == len(host) == 3
+    assert len({r["pid"] for r in dev}) == 1
+    assert all(a["t1"] <= b["t0"] for a, b in zip(dev, dev[1:]))
+    assert len({r["pid"] for r in host} | {dev[0]["pid"]}) == 4
+    assert os.getpid() not in {r["pid"] for r in recs}
+
+
+def test_device_lane_continues_after_crash():
+    cells = expand_campaign(CampaignSpec(name="t", grids=(LANE_GRID,)))
+    recs = run_cells(cells, quick=True, workers=2, cell_timeout_s=60.0,
+                     cell_fn=_crash_on_jax_seed2)
+    dev = {r["seed"]: r for r in recs if r["engine"] == "jax"}
+    assert dev[2]["status"] == "crash" and dev[2]["exitcode"] == 5
+    assert dev[1]["status"] == dev[3]["status"] == "ok"
+    assert dev[1]["pid"] != dev[3]["pid"]      # the lane went on anew
+    assert all(r["status"] == "ok" for r in recs if r["engine"] != "jax")
 
 
 # ----------------------------------------------------------- bench I/O

@@ -207,7 +207,8 @@ def test_pallas_scale_matches_numpy():
     capacity = np.maximum(units, 1) * fb.unit_rate
     got = _pallas_latency_scale(
         fb.base_pf.astype(np.float32), fb.alpha.astype(np.float32),
-        demand.astype(np.float32), capacity.astype(np.float32))
+        demand.astype(np.float32), capacity.astype(np.float32),
+        interpret=True)
     np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-5)
 
 

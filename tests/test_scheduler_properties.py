@@ -74,12 +74,11 @@ def test_compressed_allreduce_multidevice():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.parallel.compression import compressed_allreduce
-        from repro.parallel.sharding import shard_map
         mesh = jax.make_mesh((8,), ("data",))
         def f(x):
             return compressed_allreduce(x, "data")
-        g = shard_map(f, mesh=mesh, in_specs=P("data"),
-                      out_specs=P("data"), axis_names={"data"})
+        g = jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                          out_specs=P("data"), axis_names={"data"})
         x = jax.random.normal(jax.random.key(0), (8, 1024))
         with mesh:
             out = jax.jit(g)(x.reshape(-1))
