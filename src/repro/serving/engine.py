@@ -17,11 +17,15 @@ every preempted request — a victim must stop decoding the moment it
 leaves the active set, or it would keep generating into a slot that
 ``free_slot`` can hand to someone else.
 
-Time: every timestamp the engine takes (arrival, first token, finish)
-comes from the injectable ``clock`` callable — ``time.perf_counter`` by
-default, or a :class:`~repro.serving.federation.VirtualClock` for
-deterministic simulation-grade runs (the serving federation's
-determinism contract).
+Time: every timestamp the engine takes (arrival, first admission,
+first token, finish) comes from the injectable ``clock`` callable —
+``time.perf_counter`` by default, or a
+:class:`~repro.serving.federation.VirtualClock` for deterministic
+simulation-grade runs (the serving federation's determinism contract).
+Its host work is also wrapped in profiler spans (``serve.*``, listed in
+:data:`repro.obs.SPAN_NAMES`), which land on the device trace's clock
+when a profiler session runs and cost about a microsecond each when none
+does.
 
 CPU-sized models validate the full control loop end-to-end; on a pod the
 same engine runs with pjit-sharded models and the Pallas paged-attention
@@ -37,6 +41,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import (DyverseController, NodeCapacity, Quota, ResourceUnit,
@@ -186,26 +191,35 @@ class MultiTenantEngine:
 
     # ------------------------------------------------------------ stepping
     def step(self) -> None:
-        for name in list(self.tenants):
-            rt = self.tenants[name]
-            # admit new requests within quota and prefill them (requests
-            # inside a retry backoff window stay queued until not_before)
-            for rs in self.sched.admit_waiting(name, self.clock()):
-                slot = rt.free_slot()
-                if slot < 0:
-                    # shouldn't happen (slots quota ≤ slot_cap) but be safe
-                    self.sched.tenants[name].active.remove(rs)
-                    rs.phase = Phase.QUEUED
-                    self.sched.tenants[name].waiting.appendleft(rs)
-                    continue
-                self._prefill_into_slot(rt, rs, slot)
-            # one decode step for all active slots
-            if any(r is not None for r in rt.slot_req):
-                self._decode_step(rt)
-        self.steps += 1
-        if self.cfg.policy != "none" and \
-                self.steps % self.cfg.round_interval_steps == 0:
-            self.ctrl.run_round()
+        with StepTraceAnnotation("serve.step", step_num=self.steps):
+            for name in list(self.tenants):
+                rt = self.tenants[name]
+                # admit new requests within quota and prefill them
+                # (requests inside a retry backoff window stay queued
+                # until not_before)
+                now = self.clock()
+                with TraceAnnotation("serve.admit", tenant=name):
+                    admitted = self.sched.admit_waiting(name, now)
+                for rs in admitted:
+                    slot = rt.free_slot()
+                    if slot < 0:
+                        # shouldn't happen (slots quota ≤ slot_cap) but
+                        # be safe
+                        self.sched.tenants[name].active.remove(rs)
+                        rs.phase = Phase.QUEUED
+                        self.sched.tenants[name].waiting.appendleft(rs)
+                        continue
+                    if rs.admit_t is None:  # the first admission stays
+                        rs.admit_t = now
+                    self._prefill_into_slot(rt, rs, slot)
+                # one decode step for all active slots
+                if any(r is not None for r in rt.slot_req):
+                    self._decode_step(rt)
+            self.steps += 1
+            if self.cfg.policy != "none" and \
+                    self.steps % self.cfg.round_interval_steps == 0:
+                with TraceAnnotation("serve.round"):
+                    self.ctrl.run_round()
 
     def _prefill_into_slot(self, rt: TenantRuntime, rs: RequestState,
                            slot: int) -> None:
@@ -220,19 +234,22 @@ class MultiTenantEngine:
             ctx = rs.req.prompt + rs.generated[:-1]
         else:
             ctx = rs.req.prompt
-        tokens = jnp.asarray(ctx, jnp.int32)[None, :]
-        batch = {"tokens": tokens}
-        if cfg.is_encoder_decoder:
-            Se = max(tokens.shape[1] // cfg.encoder_seq_ratio, 1)
-            batch["frames"] = jnp.zeros((1, Se, cfg.d_model), jnp.bfloat16)
-        logits, cache1 = rt._prefill(rt.params, batch)
-        rt.cache = _insert_cache(rt.cache, cache1, slot, cfg,
-                                 self.cfg.max_seq_len)
-        if resumed:
-            tok = rs.generated[-1]
-        else:
-            tok = int(jnp.argmax(logits[0]))
-            rs.generated.append(tok)
+        with TraceAnnotation("serve.prefill", tenant=rt.name,
+                             rid=rs.req.rid, tokens=len(ctx)):
+            tokens = jnp.asarray(ctx, jnp.int32)[None, :]
+            batch = {"tokens": tokens}
+            if cfg.is_encoder_decoder:
+                Se = max(tokens.shape[1] // cfg.encoder_seq_ratio, 1)
+                batch["frames"] = jnp.zeros((1, Se, cfg.d_model),
+                                            jnp.bfloat16)
+            logits, cache1 = rt._prefill(rt.params, batch)
+            rt.cache = _insert_cache(rt.cache, cache1, slot, cfg,
+                                     self.cfg.max_seq_len)
+            if resumed:
+                tok = rs.generated[-1]
+            else:
+                tok = int(jnp.argmax(logits[0]))
+                rs.generated.append(tok)
         if rs.first_token_t is None:     # TTFT survives preemption
             rs.first_token_t = self.clock()
         rs.phase = Phase.DECODE
@@ -242,28 +259,32 @@ class MultiTenantEngine:
         rt.last_token[slot] = tok
 
     def _decode_step(self, rt: TenantRuntime) -> None:
-        token = jnp.asarray(rt.last_token, jnp.int32)
-        pos = jnp.asarray(rt.pos, jnp.int32)
-        logits, rt.cache = rt._decode(rt.params, rt.cache, token, pos)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        t_done = self.clock()
-        for slot, rs in enumerate(rt.slot_req):
-            if rs is None:
-                continue
-            rs.generated.append(int(nxt[slot]))
-            rt.pos[slot] += 1
-            rt.last_token[slot] = int(nxt[slot])
-            done = (len(rs.generated) >= rs.req.max_new_tokens
-                    or rt.pos[slot] >= self.cfg.max_seq_len - 1)
-            if done:
-                self.sched.finish(rt.name, rs, t_done)
-                st = self.ctrl.registry.get(rt.name)
-                if st is not None:
-                    self.ctrl.monitor.record_request(
-                        rt.name, rs.latency(), st.spec.slo_latency,
-                        data_mb=len(rs.generated) * 4e-6, user=rs.req.user)
-                rt.slot_req[slot] = None
-                self.completed.append(rs)
+        live = sum(r is not None for r in rt.slot_req)
+        with TraceAnnotation("serve.decode", tenant=rt.name, live=live):
+            token = jnp.asarray(rt.last_token, jnp.int32)
+            pos = jnp.asarray(rt.pos, jnp.int32)
+            logits, rt.cache = rt._decode(rt.params, rt.cache, token, pos)
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with TraceAnnotation("serve.commit", tenant=rt.name):
+            t_done = self.clock()
+            for slot, rs in enumerate(rt.slot_req):
+                if rs is None:
+                    continue
+                rs.generated.append(int(nxt[slot]))
+                rt.pos[slot] += 1
+                rt.last_token[slot] = int(nxt[slot])
+                done = (len(rs.generated) >= rs.req.max_new_tokens
+                        or rt.pos[slot] >= self.cfg.max_seq_len - 1)
+                if done:
+                    self.sched.finish(rt.name, rs, t_done)
+                    st = self.ctrl.registry.get(rt.name)
+                    if st is not None:
+                        self.ctrl.monitor.record_request(
+                            rt.name, rs.latency(), st.spec.slo_latency,
+                            data_mb=len(rs.generated) * 4e-6,
+                            user=rs.req.user)
+                    rt.slot_req[slot] = None
+                    self.completed.append(rs)
 
     def run(self, steps: int) -> None:
         for _ in range(steps):

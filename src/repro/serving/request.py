@@ -29,6 +29,9 @@ class RequestState:
     phase: Phase = Phase.QUEUED
     generated: list[int] = field(default_factory=list)
     batch_slot: int = -1         # slot in the tenant's decode batch
+    # first move from the queue to the active set; like first_token_t it
+    # survives preemption, requeue and migration
+    admit_t: float | None = None
     first_token_t: float | None = None
     finish_t: float | None = None
     # resilience (serving federation timeouts): a request not finished

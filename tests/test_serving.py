@@ -302,3 +302,74 @@ def test_eviction_while_all_requests_waiting():
     assert all(r.finish_t == clock() + CLOUD_LATENCY_S for r in rs)
     assert all(r.latency() == CLOUD_LATENCY_S for r in rs)
     assert eng.completed == []
+
+
+# ------------------------------------------------ admission stamp and spans
+def test_admit_stamp_set_once_through_preemption_and_requeue():
+    """``admit_t`` is the first move from the queue to the active set:
+    arrival ≤ admit ≤ first token, and neither a preemption nor a
+    requeue (the federation's timeout / migration path) restamps it."""
+    from repro.serving.spec import VirtualClock
+
+    clock = VirtualClock(0.25)
+    eng = MultiTenantEngine(_tiny_cfg(), seed=3, clock=clock)
+    assert eng.add_tenant(TenantSpec(name="t", slo_latency=60.0),
+                          get_reduced("tinyllama-1.1b"))
+    r = eng.submit("t", [5, 7, 9, 11], max_new_tokens=12)
+    assert r.admit_t is None
+    clock.tick()
+    eng.step()
+    admitted = r.admit_t
+    assert admitted == 0.25
+    assert r.req.arrival_t <= admitted <= r.first_token_t
+    for _ in range(2):
+        clock.tick()
+        eng.step()
+    # preempted and re-admitted
+    eng.ctrl.actuator.apply_quota("t", Quota(slots=0, pages=64))
+    assert r.phase == Phase.QUEUED
+    clock.tick()
+    eng.step()
+    eng.ctrl.actuator.apply_quota("t", Quota(slots=2, pages=64))
+    clock.tick()
+    eng.step()
+    assert r.phase == Phase.DECODE and r.admit_t == admitted
+    # pulled out mid-decode and requeued, as the federation does
+    tq, rt = eng.sched.tenants["t"], eng.tenants["t"]
+    tq.active.remove(r)
+    rt.slot_req[r.batch_slot] = None
+    r.generated.clear()
+    eng.sched.requeue(r)
+    while r.phase != Phase.DONE:
+        clock.tick()
+        eng.step()
+    assert r.admit_t == admitted
+    assert r.req.arrival_t <= r.admit_t <= r.first_token_t
+
+
+def test_span_names_pinned_by_a_recorded_trace(tmp_path):
+    """Every host span a traced engine writes is in ``SPAN_NAMES`` and
+    every name there is written: one tenant, two requests, a DYVERSE
+    round every two steps."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.obs import SPAN_NAMES
+
+    eng = MultiTenantEngine(_tiny_cfg(policy="sdps", round_interval_steps=2))
+    assert eng.add_tenant(TenantSpec(name="t", slo_latency=60.0),
+                          get_reduced("tinyllama-1.1b"))
+    eng.submit("t", [1, 2, 3, 4], max_new_tokens=3)
+    eng.drain(max_steps=20)                 # compile outside the trace
+    rs = [eng.submit("t", [5, 6, 7, 8], max_new_tokens=3) for _ in range(2)]
+    jax.profiler.start_trace(str(tmp_path))
+    eng.drain(max_steps=20)
+    jax.profiler.stop_trace()
+    assert all(r.phase == Phase.DONE for r in rs)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    seen = {ev.name for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("serve.")}
+    assert seen == SPAN_NAMES
