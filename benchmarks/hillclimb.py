@@ -87,12 +87,6 @@ RUNS = [
     ("C", "granite-8b", "decode_32k", "opt2_partials_bf16",
      {"num_layers": 12, "decode_partials": True, "bf16_reduce": True},
      "bf16 boundary sums for the tiny per-token activations too."),
-    ("C", "granite-8b", "decode_32k", "opt3_grouped",
-     {"num_layers": 12, "decode_partials": True, "decode_grouped": True},
-     "ROUND 2: KH-grouped decode einsums — never materialise the "
-     "(B,S,H,D) repeat_kv; cache is read at native KH width. Memory term "
-     "should approach pure param+cache streaming (predict ~2-3x down; "
-     "the Pallas paged_attention kernel realises the same on real TPU)."),
 ]
 
 

@@ -51,8 +51,6 @@ class ModelConfig:
                                    # with partial-softmax combine
     attn_bf16_probs: bool = False  # PV matmul reads bf16 probabilities
                                    # (accumulators stay f32)
-    decode_grouped: bool = False   # GQA decode without repeat_kv
-                                   # materialisation (KH-grouped einsums)
     # SSM (mamba2 / hybrid)
     ssm_state: int = 0               # N: state size per head
     ssm_head_dim: int = 0            # P: channels per SSM head

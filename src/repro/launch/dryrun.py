@@ -280,7 +280,6 @@ def main():
     ap.add_argument("--bf16-reduce", action="store_true")
     ap.add_argument("--seq-parallel", action="store_true")
     ap.add_argument("--decode-partials", action="store_true")
-    ap.add_argument("--decode-grouped", action="store_true")
     ap.add_argument("--attn-bf16-probs", action="store_true")
     ap.add_argument("--attn-chunk", type=int, default=None)
     ap.add_argument("--capacity-factor", type=float, default=None)
@@ -303,8 +302,6 @@ def main():
         overrides["seq_parallel"] = True
     if args.decode_partials:
         overrides["decode_partials"] = True
-    if args.decode_grouped:
-        overrides["decode_grouped"] = True
     if args.attn_bf16_probs:
         overrides["attn_bf16_probs"] = True
     if args.attn_chunk:

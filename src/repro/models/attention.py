@@ -7,7 +7,9 @@ materialisation) so the head axis shards cleanly over "model" whenever
 H divides the axis; scan carries are sharding-constrained to stop GSPMD
 replicating the online-softmax state (which would insert per-chunk
 all-reduces). The chunked path is the pure-JAX analogue of the Pallas
-flash kernel in ``repro.kernels.flash_attention``.
+flash kernel in ``repro.kernels.flash_attention``. Decode attention
+instead groups the query heads by KV head and reads the cache once, at
+its KV-head width and dtype.
 """
 from __future__ import annotations
 
@@ -158,10 +160,17 @@ def swa_attention(q, k, v, *, window: int):
 
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,
-                     partials: bool = False, grouped: bool = False):
+                     partials: bool = False):
     """Single-token decode. q (B,1,H,D); caches (B,Smax,KH,D); cache_len
     (B,) or scalar — number of valid positions (new token's K/V already
     written at cache_len-1). For SWA the cache is a ring buffer.
+
+    Queries are grouped by KV head, (B,KH,G,D) with G = H // KH, and
+    contracted against the cache in its own layout and dtype: the cache is
+    read once at KV-head width, never repeated to H heads or copied to
+    float32. Scores and the softmax are float32 (products of the cache's
+    bf16 are exact there); P·V keeps float32 probabilities and widens the
+    cache inside the dot (``HIGHEST``).
 
     ``partials`` (flash-decoding layout): the logits stay SEQ-sharded over
     "model" (matching the seq-sharded cache) and only the softmax
@@ -169,38 +178,20 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,
     resharding the whole cache onto the heads layout."""
     B, Smax, KH, D = k_cache.shape
     H = q.shape[2]
-    scale = D ** -0.5
-    pos = jnp.arange(Smax)
-    valid = pos[None, :] < jnp.asarray(cache_len).reshape(-1, 1)
-    if grouped:
-        # KH-grouped einsums: never materialise the (B,S,H,D) repeat — the
-        # cache is read once at its native KH width (memory-term win)
-        G = H // KH
-        qg = q.reshape(B, 1, KH, G, D).astype(jnp.float32) * scale
-        if partials:
-            qg = constrain(qg, "batch", None, None, None, None)
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_cache.astype(jnp.float32))
-        if partials:
-            s = constrain(s, "batch", None, None, None, "model")
-        s = jnp.where(valid[:, None, None, None], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bhgqk,bkhd->bhgqd", p, v_cache.astype(jnp.float32))
-        out = out.transpose(0, 3, 1, 2, 4).reshape(B, 1, H, D)
-        if partials:
-            out = constrain(out, "batch", None, None, None)
-        return out.astype(q.dtype)
-    k_cache = repeat_kv(k_cache, H)
-    v_cache = repeat_kv(v_cache, H)
-    qf = q.astype(jnp.float32) * scale
+    qg = q.reshape(B, KH, H // KH, D)
     if partials:
-        qf = constrain(qf, "batch", None, None, None)   # q replicated on model
-    s = jnp.einsum("bqhd,bkhd->bhqk", qf, k_cache.astype(jnp.float32))
+        qg = constrain(qg, "batch", None, None, None)   # q replicated on model
+    s = jnp.einsum("bhgd,bkhd->bhgk", qg, k_cache,
+                   preferred_element_type=jnp.float32) * D ** -0.5
     if partials:
         s = constrain(s, "batch", None, None, "model")  # seq-sharded logits
+    valid = jnp.arange(Smax)[None, :] < jnp.asarray(cache_len).reshape(-1, 1)
     s = jnp.where(valid[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bhqd", p, v_cache.astype(jnp.float32))
-    out = out.transpose(0, 2, 1, 3)
+    out = jnp.einsum("bhgk,bkhd->bhgd", p, v_cache,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    out = out.reshape(B, 1, H, D)
     if partials:
         out = constrain(out, "batch", None, None, None)
     return out.astype(q.dtype)

@@ -139,8 +139,7 @@ def decode_step_stack(params_stacked, x, cfg: ModelConfig, cache, pos):
         q, k, v = attn.qkv_proj(p_l["attn"], hh, cfg, positions=pos[:, None])
         kc, vc = write_slot((kc, vc), k, v, slot)
         o = attn.decode_attention(q, kc, vc, cache_len, window=window,
-                                  partials=cfg.decode_partials,
-                                  grouped=cfg.decode_grouped)
+                                  partials=cfg.decode_partials)
         B = h.shape[0]
         h = h + o.reshape(B, 1, cfg.q_dim) @ p_l["attn"]["wo"].astype(cdtype(cfg))
         f, _ = _ffn(p_l, apply_norm(p_l["ln2"], h, cfg), cfg)

@@ -3,6 +3,9 @@
 Key invariant: prefill(tokens[:S]) then decode(token[S]) must produce the
 same logits as a full forward over tokens[:S+1] at the last position.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,8 +13,8 @@ import pytest
 
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.models import build_model
-from repro.models.attention import (chunked_attention, full_attention_reference,
-                                    swa_attention)
+from repro.models.attention import (chunked_attention, decode_attention,
+                                    full_attention_reference, swa_attention)
 from repro.models.mamba2 import ssd_chunked, ssd_reference
 from repro.models.moe import moe_ffn, moe_ffn_dense_reference, moe_params
 
@@ -116,6 +119,92 @@ def test_swa_attention_matches_reference():
         ref = full_attention_reference(q, k, v, causal=True, window=w)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
+
+
+DECODE_HEADS = [(4, 4, 16), (8, 2, 16), (32, 8, 120)]   # (H, KH, D)
+
+
+def _decode_case(key, H, KH, D, B, T):
+    """bf16 queries (B, 1, H, D) and keys/values (B, T, KH, D)."""
+    ks = jax.random.split(key, 3)
+    q = jax.random.normal(ks[0], (B, 1, H, D)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, T, KH, D)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, T, KH, D)).astype(jnp.bfloat16)
+    return q, k, v
+
+
+def _decode_reference(q, k, v, lengths, window=0):
+    """Each slot's last query row of ``full_attention_reference`` over its
+    own ``lengths[b]`` keys, in float32."""
+    rows = [full_attention_reference(q[b:b + 1].astype(jnp.float32),
+                                     k[b:b + 1, :n], v[b:b + 1, :n],
+                                     causal=True, window=window)
+            for b, n in enumerate(lengths)]
+    return np.asarray(jnp.concatenate(rows), np.float32)
+
+
+@pytest.mark.parametrize("ragged", [True, False], ids=["per_slot", "scalar"])
+@pytest.mark.parametrize("H,KH,D", DECODE_HEADS)
+def test_decode_attention_matches_reference(H, KH, D, ragged):
+    """Grouped decode over a dense bf16 cache == the reference, each slot
+    masked to its own ``cache_len`` (per slot or one scalar); the cache
+    rows past it hold other keys, which the mask must hide."""
+    lengths = [7, 40, 23] if ragged else [29, 29, 29]
+    q, k, v = _decode_case(jax.random.key(H * 100 + KH), H, KH, D,
+                           len(lengths), 40)
+    cache_len = jnp.asarray(lengths, jnp.int32) if ragged else lengths[0]
+    out = decode_attention(q, k, v, cache_len)
+    assert out.shape == q.shape and out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               _decode_reference(q, k, v, lengths),
+                               rtol=2 ** -8, atol=1e-5)
+
+
+@pytest.mark.parametrize("H,KH,D", DECODE_HEADS)
+def test_decode_attention_swa_ring_buffer(H, KH, D):
+    """SWA: the cache is a ring buffer of ``window`` < S positions (token t
+    at t % window); decode sees each slot's last ``window`` tokens."""
+    window = 16
+    lengths = [9, 16, 21, 40]           # not yet wrapped, full, wrapped
+    q, k, v = _decode_case(jax.random.key(H + KH), H, KH, D, len(lengths),
+                           max(lengths))
+    kc = jnp.zeros((len(lengths), window, KH, D), jnp.bfloat16)
+    vc = jnp.zeros_like(kc)
+    for b, n in enumerate(lengths):
+        for t in range(n):
+            kc = kc.at[b, t % window].set(k[b, t])
+            vc = vc.at[b, t % window].set(v[b, t])
+    cache_len = jnp.minimum(jnp.asarray(lengths, jnp.int32), window)
+    out = decode_attention(q, kc, vc, cache_len, window=window)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               _decode_reference(q, k, v, lengths, window),
+                               rtol=2 ** -8, atol=1e-5)
+
+
+def test_decode_reads_cache_at_kv_head_width():
+    """The lowered decode step of a GQA model holds no array with the
+    cache's length and all query heads after it: neither the repeat
+    (…, S, H, D) nor its broadcast (…, S, KH, G, D)."""
+    cfg = dataclasses.replace(get_reduced("tinyllama-1.1b"), num_heads=12,
+                              num_kv_heads=3, head_dim=20)
+    H, KH = cfg.num_heads, cfg.num_kv_heads
+    G = H // KH
+    Bz, smax = 5, 56
+    m = build_model(cfg)
+    params = jax.eval_shape(m.init_params, jax.random.key(0))
+    cache = m.cache_specs(Bz, smax)
+    tok = jax.ShapeDtypeStruct((Bz,), jnp.int32)
+    text = jax.jit(m.decode_fn).lower(params, cache, tok, tok).as_text()
+    shapes = [[int(d) for d in dims.split("x")[:-1]]
+              for dims in re.findall(r"tensor<((?:\d+x)+)\w+>", text)]
+    assert any(smax in dims for dims in shapes)   # the cache is there
+    for dims in shapes:
+        if smax not in dims:
+            continue
+        after = dims[dims.index(smax) + 1:]
+        assert H not in after, dims
+        assert not any(after[i:i + 2] == [KH, G]
+                       for i in range(len(after))), dims
 
 
 def test_ssd_chunked_matches_scan():

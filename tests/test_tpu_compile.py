@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
+from repro.models import attention
 from repro.sim.engines import jax_backend as jb
 
 ROWS = 100_000          # a 4 x 25,000-tenant stream fleet
@@ -124,3 +125,19 @@ def test_pallas_latency_scale(one_chip):
     compiled = jb._pallas_latency_scale.lower(
         col, col, demand, col, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_attention_danube3_4b(one_chip):
+    """Decode attention at the serving cell's widths (8 slots of 2,176
+    positions) reads the bf16 cache in place: its temporaries stay below
+    one bf16 copy of the cache, so nothing of cache size is repeated to
+    the query heads or widened to float32."""
+    cfg = get_config("h2o-danube-3-4b")
+    B, S = 8, 2176
+    q = _sds((B, 1, cfg.num_heads, cfg.head_dim), jnp.bfloat16, one_chip)
+    cache = _sds((B, S, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16,
+                 one_chip)
+    lengths = _sds((B,), jnp.int32, one_chip)
+    compiled = _compile(attention.decode_attention, q, cache, cache, lengths)
+    cache_bytes = B * S * cfg.num_kv_heads * cfg.head_dim * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes
