@@ -12,7 +12,8 @@ TINY_MODEL = {"hidden_size": 64, "intermediate_size": 128,
               "tie_word_embeddings": False}
 
 TINY_SERVING = {
-    "kind": "serving", "model": TINY_MODEL, "tenants": 2,
+    "kind": "serving", "module": "tpu_bench.danube", "model": TINY_MODEL,
+    "tenants": 2,
     "program": {"arch": "h2o-danube-3-4b",
                 "settings": {"num_layers": 2, "d_model": 64, "d_ff": 128,
                              "num_heads": 4, "num_kv_heads": 2,

@@ -9,7 +9,7 @@ import sys
 import pytest
 
 from tpu_bench.common import (BENCH_DIR, ROOT, benchmark, cell_metrics,
-                              find_cell, metric_reader, peaks)
+                              find_cell, metric_reader, model_module, peaks)
 
 BENCH = benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -32,12 +32,50 @@ def test_every_cell_builds_from_its_files(cell):
         cfg = model_config(conf)
         assert cfg.num_layers == conf["model"]["num_hidden_layers"]
         assert set(conf["reduced"]) <= set(conf["published"])
-    else:
+    elif conf["kind"] == "fleet":
         from tpu_bench.fleet import build_fleet
 
         small = dict(conf, tenants_per_node=3)
         wls, table = build_fleet(small, traffic, 1)
         assert len(wls) == 3 * conf["nodes"] and len(table["index"]) == len(wls)
+
+
+SERVING = [w["name"] for w in BENCH["workloads"]
+           if find_cell(w["name"], BENCH)[1]["kind"] == "serving"]
+
+
+@pytest.mark.parametrize("cell", SERVING)
+def test_every_serving_model_module_keeps_to_the_contract(cell):
+    """The module that a serving configuration names gives, without
+    making a weight: the program's parameter tree, leaf for leaf, in
+    the served type; reference logits at the checked positions of the
+    mix's reference length, with the fp8 control; and operation and
+    byte counts that are positive and grow with the work."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import build_model
+
+    _, conf, traffic = find_cell(cell, BENCH)
+    arch, model = model_module(conf), conf["model"]
+    cfg = arch.program_config(conf)
+    want = jax.eval_shape(build_model(cfg).init_params, jax.random.key(0))
+    got = jax.eval_shape(lambda: arch.make_weights(model, 2**33 + 1, 1))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    assert all(g.shape == w.shape and g.dtype == jnp.dtype(cfg.param_dtype)
+               for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
+    toks = jax.ShapeDtypeStruct((traffic["reference_len"],), jnp.int32)
+    sel = jax.ShapeDtypeStruct((traffic["output"]["max"],), jnp.int32)
+    for fp8 in (False, True):
+        out = jax.eval_shape(lambda p, t, s: arch.reference_logits(
+            model, p, t, s, fp8=fp8), got, toks, sel)
+        assert out.shape == (sel.shape[0], model["vocab_size"])
+        assert out.dtype == jnp.float32
+    S = traffic["prompt"]["buckets"]
+    for count in (arch.prefill_flops, arch.prefill_bytes):
+        assert 0 < count(model, S[0]) < count(model, S[-1])
+    ctx = [S[-1]] * conf["engine"]["slot_cap"]
+    for count in (arch.decode_flops, arch.decode_bytes):
+        assert 0 < count(model, ctx[:1]) < count(model, ctx)
 
 
 def test_names_units_and_bounds_keep_to_the_contract():

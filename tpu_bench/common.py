@@ -2,10 +2,12 @@
 its statistics, and the result line.
 
 Nothing here imports the program under test (``src/repro``): the cell
-modules (``serving.py``, ``fleet.py``) do, and only for the system itself.
+modules (``serving.py``, ``fleet.py``) and a serving configuration's
+model module do, and only for the system itself.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import sys
@@ -28,14 +30,54 @@ def benchmark() -> dict:
 
 def find_cell(name: str, bench: dict | None = None):
     """(workload entry, configuration file, traffic file) of one cell,
-    each found by the name ``BENCHMARK.json`` gives it."""
+    each found by the name ``BENCHMARK.json`` gives it. The configuration
+    keeps the path it was read from under ``file``, for its errors."""
     bench = bench or benchmark()
     for w in bench["workloads"]:
         if w["name"] == name:
-            conf = next(c for c in bench["configs"] if c["name"] == w["config"])
-            return (w, load_json(ROOT / conf["file"]),
+            entry = next(c for c in bench["configs"]
+                         if c["name"] == w["config"])
+            conf = dict(load_json(ROOT / entry["file"]), file=entry["file"])
+            return (w, conf,
                     load_json(BENCH_DIR / "traffic" / f"{w['traffic']}.json"))
     raise KeyError(f"no workload {name!r} in BENCHMARK.json")
+
+
+#: what the model module of a serving configuration exports:
+#: ``program_config(conf)``, the program's ``ModelConfig`` for the file;
+#: ``make_weights(model, seed, tenant)``, one tenant's served weights in
+#: the program's parameter tree, made on the device from the seed;
+#: ``reference_logits(model, params, tokens, sel, *, fp8=False)``, the
+#: plain float32 forward pass and its fp8 control; and the operations and
+#: bytes of each measured call: ``prefill_flops(model, S)``,
+#: ``prefill_bytes(model, S)``, ``decode_flops(model, contexts)``,
+#: ``decode_bytes(model, contexts)``.
+MODEL_CONTRACT = ("program_config", "make_weights", "reference_logits",
+                  "prefill_flops", "prefill_bytes", "decode_flops",
+                  "decode_bytes")
+
+
+def model_module(conf: dict):
+    """The model module that a serving configuration names under
+    ``module`` (an import path). A configuration that names none, or a
+    module that cannot be imported or lacks part of ``MODEL_CONTRACT``,
+    is an error that names the configuration's file: there is no
+    default model."""
+    where = conf.get("file", "serving configuration (read from no file)")
+    name = conf.get("module")
+    if not isinstance(name, str) or not name:
+        raise ValueError(f"{where}: names no model module under 'module'")
+    try:
+        mod = importlib.import_module(name)
+    except ImportError as e:
+        raise ImportError(f"{where}: cannot import its model module "
+                          f"{name!r}: {e}") from e
+    missing = [f for f in MODEL_CONTRACT
+               if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ValueError(f"{where}: model module {name!r} lacks "
+                         f"{', '.join(missing)}")
+    return mod
 
 
 def cell_metrics(bench: dict, cell: str, section: str) -> list[dict]:

@@ -36,11 +36,8 @@ def serving_readings(conf, traffic, seed, seconds) -> dict:
     chk = traffic["check"]
     sample = serving.sample_for_check(w, seed, chk["tokens"],
                                       chk["max_requests"])
-    model = dict(conf["model"], max_check_tokens=traffic["output"]["max"])
-    prog = serving.reference_gaps(model, seed, sample,
-                                  traffic["reference_len"])
-    ctl = serving.reference_gaps(model, seed, sample,
-                                 traffic["reference_len"], fp8=True)
+    prog = serving.reference_gaps(conf, traffic, seed, sample)
+    ctl = serving.reference_gaps(conf, traffic, seed, sample, fp8=True)
     return {"program": {"max_logit_gap": max(prog)},
             "control": {"max_logit_gap": max(ctl)},
             "per_request": {"program": prog, "control": ctl},

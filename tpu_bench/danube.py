@@ -14,17 +14,48 @@ engine serves them, laid out as the serving engine's parameter tree
 (``tok``/``layers``/``ln_f``; norm weights stored as ``scale`` with
 ``w = 1 + scale``). The engine's own parameters are replaced by these, so
 the reference can make the very same ones again without reading
-anything the program made. Nothing here imports the program.
+anything the program made.
+
+This is the model module of the configurations that name it under
+``module`` (``common.MODEL_CONTRACT``): ``program_config`` maps the file
+onto the program's preset, the only use of the program here; the
+weights and the reference import nothing of it; the operation and byte
+counts are ``counters.py``'s dense decoder.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 
+from tpu_bench.counters import (decode_bytes, decode_flops,  # noqa: F401
+                                prefill_bytes, prefill_flops)
+
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
+
+
+def program_config(conf: dict):
+    """The program's ``ModelConfig`` for this configuration file: its
+    preset for the architecture, with the file's ``program`` settings."""
+    from repro.configs import get_config
+
+    cfg = dataclasses.replace(get_config(conf["program"]["arch"]),
+                              **conf["program"]["settings"])
+    m = conf["model"]
+    want = {"d_model": m["hidden_size"], "d_ff": m["intermediate_size"],
+            "num_heads": m["num_attention_heads"],
+            "num_kv_heads": m["num_key_value_heads"],
+            "head_dim": m["head_dim"], "num_layers": m["num_hidden_layers"],
+            "vocab_size": m["vocab_size"], "rope_theta": m["rope_theta"],
+            "tie_embeddings": m["tie_word_embeddings"]}
+    got = {k: getattr(cfg, k) for k in want}
+    if got != want or cfg.padded_vocab != m["vocab_size"]:
+        raise ValueError(f"program config {got} departs from the "
+                         f"configuration file {want}")
+    return cfg
 
 
 def _sizes(m: dict):
@@ -77,7 +108,7 @@ def make_weights(model: dict, seed: int, tenant: int):
 
 
 # ------------------------------------------------------------ reference
-def _q8(x, axis):
+def q8(x, axis):
     """Round to fp8 (e4m3) with one absmax scale per slice along ``axis``
     (the contracted axis), back in float32."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / F8_MAX
@@ -85,12 +116,12 @@ def _q8(x, axis):
     return (x / s).astype(F8).astype(jnp.float32) * s
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     w = 1.0 + scale.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x (S, heads, hd) at positions 0..S-1, rotate-half convention."""
     S, _, hd = x.shape
     half = hd // 2
@@ -101,30 +132,42 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.lru_cache(maxsize=None)
-def _forward_fn(sizes: tuple, theta: float, eps: float, fp8: bool):
-    D, F, H, KH, hd, L, V = sizes
-    G = H // KH
-
+def matmul(fp8: bool):
+    """The reference's matmul in float32, or with both operands first
+    rounded to fp8 along the contracted axis (the control)."""
     def mm(a, w):
         a, w = a.astype(jnp.float32), w.astype(jnp.float32)
         if fp8:
-            a, w = _q8(a, -1), _q8(w, 0)
+            a, w = q8(a, -1), q8(w, -2)
         return a @ w
+    return mm
+
+
+def attend(h, p, heads: int, kv_heads: int, hd: int, theta: float, mm):
+    """Causal grouped-query attention of one sequence ``h`` (S, D) with
+    RoPE, through the output projection: ``Wo·attn(...)``."""
+    S = h.shape[0]
+    q = rope(mm(h, p["wq"]).reshape(S, heads, hd), theta)
+    k = rope(mm(h, p["wk"]).reshape(S, kv_heads, hd), theta)
+    v = mm(h, p["wv"]).reshape(S, kv_heads, hd)
+    G = heads // kv_heads
+    k, v = jnp.repeat(k, G, axis=1), jnp.repeat(v, G, axis=1)
+    s = jnp.einsum("qhd,khd->hqk", q, k) * hd ** -0.5
+    causal = jnp.tril(jnp.ones((S, S), bool))
+    s = jnp.where(causal[None], s, -jnp.inf)
+    o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), v)
+    return mm(o.reshape(S, heads * hd), p["wo"])
+
+
+@functools.lru_cache(maxsize=None)
+def _forward_fn(sizes: tuple, theta: float, eps: float, fp8: bool):
+    D, F, H, KH, hd, L, V = sizes
+    mm = matmul(fp8)
 
     def layer(x, p):
-        S = x.shape[0]
-        h = _rms(x, p["ln1"]["scale"], eps)
-        q = _rope(mm(h, p["attn"]["wq"]).reshape(S, H, hd), theta)
-        k = _rope(mm(h, p["attn"]["wk"]).reshape(S, KH, hd), theta)
-        v = mm(h, p["attn"]["wv"]).reshape(S, KH, hd)
-        k, v = jnp.repeat(k, G, axis=1), jnp.repeat(v, G, axis=1)
-        s = jnp.einsum("qhd,khd->hqk", q, k) * hd ** -0.5
-        causal = jnp.tril(jnp.ones((S, S), bool))
-        s = jnp.where(causal[None], s, -jnp.inf)
-        o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), v)
-        x = x + mm(o.reshape(S, H * hd), p["attn"]["wo"])
-        h = _rms(x, p["ln2"]["scale"], eps)
+        h = rms(x, p["ln1"]["scale"], eps)
+        x = x + attend(h, p["attn"], H, KH, hd, theta, mm)
+        h = rms(x, p["ln2"]["scale"], eps)
         f = jax.nn.silu(mm(h, p["mlp"]["w_gate"])) * mm(h, p["mlp"]["w_up"])
         return x + mm(f, p["mlp"]["w_down"]), None
 
@@ -132,7 +175,7 @@ def _forward_fn(sizes: tuple, theta: float, eps: float, fp8: bool):
         """Logits (len(sel), V) at positions ``sel`` of ``tokens``."""
         x = params["tok"]["embed"][tokens].astype(jnp.float32)
         x, _ = jax.lax.scan(layer, x, params["layers"])
-        x = _rms(x[sel], params["ln_f"]["scale"], eps)
+        x = rms(x[sel], params["ln_f"]["scale"], eps)
         return mm(x, params["tok"]["unembed"])
     return jax.jit(forward)
 

@@ -1,7 +1,10 @@
-"""Readers shared by the serving cells' per-layer metrics."""
+"""Readers shared by the serving cells' per-layer metrics. A call's
+operations and bytes come from the model module that the cell's
+configuration names (``common.model_module``)."""
 from __future__ import annotations
 
 from tpu_bench import counters
+from tpu_bench.common import model_module
 
 PROGRAMS = {"prefill": "jit_prefill_fn", "decode": "jit_decode_fn"}
 
@@ -24,13 +27,13 @@ def device_per_call(ctx, kind: str):
 
 
 def least_time(ctx, call) -> float:
-    model = ctx.conf["model"]
+    arch, model = model_module(ctx.conf), ctx.conf["model"]
     if call[0] == "prefill":
-        f, b = (counters.prefill_flops(model, call[2]),
-                counters.prefill_bytes(model, call[2]))
+        f, b = (arch.prefill_flops(model, call[2]),
+                arch.prefill_bytes(model, call[2]))
     else:
-        f, b = (counters.decode_flops(model, call[2]),
-                counters.decode_bytes(model, call[2]))
+        f, b = (arch.decode_flops(model, call[2]),
+                arch.decode_bytes(model, call[2]))
     return counters.roofline_s(f, b, ctx.peaks)
 
 
@@ -47,7 +50,7 @@ def call_ms(ctx, kind: str):
 
 
 def model_flops(ctx) -> float:
-    model = ctx.conf["model"]
-    return sum(counters.prefill_flops(model, c[2]) if c[0] == "prefill"
-               else counters.decode_flops(model, c[2])
+    arch, model = model_module(ctx.conf), ctx.conf["model"]
+    return sum(arch.prefill_flops(model, c[2]) if c[0] == "prefill"
+               else arch.decode_flops(model, c[2])
                for c in ctx.out["window"]["calls"])

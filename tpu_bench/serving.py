@@ -6,7 +6,9 @@ warms every shape the traffic uses. The window then sends each request
 when it is due, calls ``MultiTenantEngine.step`` until ``--seconds`` have
 passed, and stamps every output token when the step that produced it
 returns. Once the window has closed, a sample of the finished requests
-is checked against the plain float32 reference (``tpu_bench.danube``).
+is checked against the plain float32 reference of the model module that
+the configuration names under ``module`` (``common.model_module``),
+which also makes the tenants' weights.
 """
 from __future__ import annotations
 
@@ -16,30 +18,13 @@ import time
 
 import numpy as np
 
-from tpu_bench import danube
-from tpu_bench.common import check, percentile, span
+from tpu_bench.common import check, model_module, percentile, span
 from tpu_bench.traffic import llm_requests
 
 
 def model_config(conf: dict):
-    """The program's ``ModelConfig`` for this configuration file: its
-    preset for the architecture, with the file's ``program`` settings."""
-    from repro.configs import get_config
-
-    cfg = dataclasses.replace(get_config(conf["program"]["arch"]),
-                              **conf["program"]["settings"])
-    m = conf["model"]
-    want = {"d_model": m["hidden_size"], "d_ff": m["intermediate_size"],
-            "num_heads": m["num_attention_heads"],
-            "num_kv_heads": m["num_key_value_heads"],
-            "head_dim": m["head_dim"], "num_layers": m["num_hidden_layers"],
-            "vocab_size": m["vocab_size"], "rope_theta": m["rope_theta"],
-            "tie_embeddings": m["tie_word_embeddings"]}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want or cfg.padded_vocab != m["vocab_size"]:
-        raise ValueError(f"program config {got} departs from the "
-                         f"configuration file {want}")
-    return cfg
+    """The program's ``ModelConfig`` for this configuration file."""
+    return model_module(conf).program_config(conf)
 
 
 @dataclasses.dataclass
@@ -59,7 +44,8 @@ class ServingCell:
 
         self.conf, self.traffic, self.seed = conf, traffic, seed
         self.model = conf["model"]
-        self.cfg = model_config(conf)
+        arch = model_module(conf)
+        self.cfg = arch.program_config(conf)
         self.eng = MultiTenantEngine(EngineConfig(**conf["engine"]),
                                      seed=seed & 0x7FFFFFFF)
         self.names = [f"t{i}" for i in range(conf["tenants"])]
@@ -73,7 +59,7 @@ class ServingCell:
                 raise RuntimeError(f"tenant {name} was not admitted")
             rt = self.eng.tenants[name]
             rt.params = None
-            rt.params = danube.make_weights(self.model, seed, i)
+            rt.params = arch.make_weights(self.model, seed, i)
             rt._prefill = self._count_prefill(rt, rt._prefill)
             rt._decode = self._count_decode(rt, rt._decode)
         set_quota = self.eng.sched.set_quota
@@ -265,36 +251,38 @@ def sample_for_check(w: dict, seed: int, tokens: int,
     return out
 
 
-def reference_gaps(model: dict, seed: int, sample: list, ref_len: int,
+def reference_gaps(conf: dict, traffic: dict, seed: int, sample: list,
                    *, fp8: bool = False) -> list[float]:
-    """For each sampled request, run the reference once over its prompt
-    and served tokens and return, per request, the widest gap by which a
+    """For each sampled request, run the configuration's reference once
+    over its prompt and served tokens, padded to the mix's
+    ``reference_len``, and return, per request, the widest gap by which a
     served token's reference logit lies below the reference's best.
 
     With ``fp8`` the control runs beside the reference, and the gap is
     that of the token the control puts first."""
     import jax.numpy as jnp
 
+    arch, model = model_module(conf), conf["model"]
     by_tenant: dict[int, list] = {}
     for s in sample:
         by_tenant.setdefault(s.req.tenant, []).append(s)
     gaps = []
     for tenant, group in sorted(by_tenant.items()):
-        params = danube.make_weights(model, seed, tenant)
+        params = arch.make_weights(model, seed, tenant)
         for s in group:
             prompt, gen = list(s.req.prompt), list(s.rs.generated)
             seq = prompt + gen[:-1]
-            toks = np.zeros(ref_len, np.int32)
+            toks = np.zeros(traffic["reference_len"], np.int32)
             toks[:len(seq)] = seq
             # the logits at position p predict the token at p + 1
-            sel = np.zeros(model["max_check_tokens"], np.int32)
+            sel = np.zeros(traffic["output"]["max"], np.int32)
             n = len(gen)
             sel[:n] = np.arange(len(prompt) - 1, len(prompt) - 1 + n)
-            ref = np.asarray(danube.reference_logits(
+            ref = np.asarray(arch.reference_logits(
                 model, params, jnp.asarray(toks), jnp.asarray(sel)))[:n]
             best = ref.max(-1)
             if fp8:
-                ctl = np.asarray(danube.reference_logits(
+                ctl = np.asarray(arch.reference_logits(
                     model, params, jnp.asarray(toks), jnp.asarray(sel),
                     fp8=True))[:n]
                 picked = ctl.argmax(-1)
@@ -344,9 +332,7 @@ def run(conf: dict, traffic: dict, seed: int, seconds: float,
     chk = traffic["check"]
     sample = sample_for_check(w, seed, chk["tokens"], chk["max_requests"])
     t_ref = time.perf_counter()
-    gaps = reference_gaps(dict(conf["model"],
-                               max_check_tokens=traffic["output"]["max"]),
-                          seed, sample, traffic["reference_len"])
+    gaps = reference_gaps(conf, traffic, seed, sample)
     ref_s = time.perf_counter() - t_ref
     checks = {
         "max_logit_gap": check(max(gaps) if gaps else float("inf"),
